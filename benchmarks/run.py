@@ -484,7 +484,6 @@ def bench_sparse_wire_sweep():
     import numpy as _np
     from jax.sharding import Mesh, PartitionSpec as P
     from repro.core import frontier as frmod
-    from repro.core.compat import shard_map
     from repro.launch.hlo_stats import collective_bytes
     from repro.launch.mesh import make_grid_mesh
 
@@ -518,8 +517,8 @@ def bench_sparse_wire_sweep():
 
     # --- measured: standalone sparse exchanges vs compiled-HLO bytes
     def hlo_total(fn, in_specs, out_specs, shapes, mesh):
-        mapped = shard_map(fn, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, check_vma=False)
+        mapped = jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                               out_specs=out_specs, check_vma=False)
         lowered = jax.jit(mapped).lower(*shapes)
         return collective_bytes(lowered.compile().as_text())["total"]
 

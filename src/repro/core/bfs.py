@@ -54,7 +54,9 @@ from jax.sharding import Mesh
 from repro.core import exchange as ex
 from repro.core import frontier as fr
 from repro.core.partition import Partition1D, Partition2D
-from repro.kernels.fold_update import fold_update
+# module import (not the function): kernels.fold_update imports
+# core.frontier, so either module may be imported first
+from repro.kernels import fold_update as fold_kernel
 
 if TYPE_CHECKING:  # graphs.formats imports core.partition; avoid the cycle
     from repro.graphs.formats import ShardedGraph
@@ -213,7 +215,8 @@ def _make_shard_fn(part: Partition1D, e_total: int, s: int,
                    queue_strategy: ex.ExchangeStrategy,
                    expand_fn=None, expand_emits_packed: bool = False,
                    n_kernel_args: int = 0, bottom_up_wire: str = "bytes",
-                   sieve: bool = False, fused: bool = False, on_trace=None):
+                   sieve: bool = False, fused: bool = False,
+                   on_tpu: bool = False, on_trace=None):
     """Builds the per-shard BFS body (runs under shard_map).
 
     Exchange strategies arrive pre-resolved from the registry (plan time),
@@ -236,7 +239,9 @@ def _make_shard_fn(part: Partition1D, e_total: int, s: int,
     (the packed bottom-up gather here; the 2-D expand allgather in
     ``_make_shard_fn_2d``) read the *carried* words — their payload is
     ready the moment the previous level's fused tail retires, with no
-    pack on the critical path between levels.
+    pack on the critical path between levels.  ``on_tpu`` (the mesh's
+    platform) runs that tail as the compiled Pallas kernel; every other
+    platform runs its fused jnp twin.
     """
     p, shard, n = part.p, part.shard_size, part.n
     itemsize = 1  # uint8 masks (the "bytes" wire format)
@@ -273,7 +278,8 @@ def _make_shard_fn(part: Partition1D, e_total: int, s: int,
                 # against dist, writes depths and emits the next packed
                 # frontier generation — no (shard, S) unpack between the
                 # collective and the next level
-                dist, new, nwords = fold_update(merged, dist, level)
+                dist, new, nwords = fold_kernel.fold_update(
+                    merged, dist, level, use_pallas=on_tpu)
                 return dist, new, nwords, jnp.float32(dense_bytes)
             own = fr.unpack_bits(merged, shard)
         else:
@@ -463,7 +469,7 @@ def _make_shard_fn_2d(part2: Partition2D, e_total: int, s: int,
                       fold_sparse_strategy: ex.ExchangeStrategy,
                       bottom_up_wire: str = "bytes",
                       sieve: bool = False, fused: bool = False,
-                      on_trace=None):
+                      on_tpu: bool = False, on_trace=None):
     """Per-device body of the 2-D two-phase BFS level loop (shard_map).
 
     Each dense level is expand -> local edge scatter -> fold -> owner
@@ -505,7 +511,8 @@ def _make_shard_fn_2d(part2: Partition2D, e_total: int, s: int,
     level L+1 ships the carried words the fused tail of level L emitted,
     so XLA can issue that collective with no pack (and, via
     ``frontier.expand_dense_2d_packed``, no row-frontier unpack) between
-    it and the previous level's update.
+    it and the previous level's update.  ``on_tpu`` picks the kernel as
+    in the 1-D builder.
     """
     r, c, b = part2.r, part2.c, part2.shard_size
     p = part2.p
@@ -562,7 +569,8 @@ def _make_shard_fn_2d(part2: Partition2D, e_total: int, s: int,
             if fused:
                 # fused fold tail: merge words -> dist depths + next
                 # packed generation in one kernel pass (no (b, S) unpack)
-                dist, new, nwords = fold_update(cw, dist, level)
+                dist, new, nwords = fold_kernel.fold_update(
+                    cw, dist, level, use_pallas=on_tpu)
                 return dist, new, nwords, dense_bytes
             own = fr.unpack_bits(cw, b)                          # (b, S)
         else:

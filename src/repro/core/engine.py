@@ -40,7 +40,6 @@ from repro.core import exchange as ex
 from repro.core import frontier as fr
 from repro.core.bfs import (BFSOptions, BFSStats, INF, _make_shard_fn,
                             _make_shard_fn_2d, validate_sources)
-from repro.core.compat import shard_map
 # chaos layer: a no-op global read unless a FaultPlan is installed
 # (stdlib-only module; degrade.py defers its engine import, no cycle)
 from repro.serve.resilience import faults as _faults
@@ -826,6 +825,9 @@ class BFSEngine:
         opts, mesh = plan_.opts, plan_.mesh
         s = plan_.num_sources
         axis = plan_.axis
+        # Pallas kernels compile for a TPU mesh; on any other platform
+        # the TPU kernels can only run in interpret mode
+        on_tpu = mesh.devices.flat[0].platform == "tpu"
 
         # The two partition schemes differ only in the per-shard loop body
         # and the edge-block encoding; everything below the dispatch —
@@ -839,7 +841,8 @@ class BFSEngine:
                 plan_.max_levels, plan_.expand_strategy, plan_.fold_strategy,
                 plan_.expand_sparse_strategy, plan_.fold_sparse_strategy,
                 bottom_up_wire=plan_.bottom_up_wire, sieve=plan_.sieve,
-                fused=plan_.use_fused_tail, on_trace=self._bump_trace)
+                fused=plan_.use_fused_tail, on_tpu=on_tpu,
+                on_trace=self._bump_trace)
             # only the auto hybrid's bottom-up level reads the in-edge
             # blocks and out-degrees; dense/queue engines neither build
             # nor upload them.  Group names carry the partition kind: a
@@ -857,7 +860,7 @@ class BFSEngine:
                 # the per-shard blocked adjacency rides the same sharded
                 # upload path as the edge blocks (one more device group)
                 expand_fn, expand_packed, kernel_arrays = \
-                    self._build_kernel_expand()
+                    self._build_kernel_expand(interpret=not on_tpu)
                 edge_groups.append(("kernel_bsr", kernel_arrays))
                 n_kernel_args = 3
             shard_fn = _make_shard_fn(
@@ -866,7 +869,8 @@ class BFSEngine:
                 expand_fn=expand_fn, expand_emits_packed=expand_packed,
                 n_kernel_args=n_kernel_args,
                 bottom_up_wire=plan_.bottom_up_wire, sieve=plan_.sieve,
-                fused=plan_.use_fused_tail, on_trace=self._bump_trace)
+                fused=plan_.use_fused_tail, on_tpu=on_tpu,
+                on_trace=self._bump_trace)
         n = part.n
 
         spec_edge = P(axis)
@@ -915,7 +919,7 @@ class BFSEngine:
                 np.arange(n) < part.n_logical, sh_edge))
         n_edge_in = len(self._gbufs)
 
-        mapped = shard_map(
+        mapped = jax.shard_map(
             shard_fn, mesh=mesh,
             in_specs=(spec_edge,) * n_edge_in + (spec_vert, spec_vert,
                                                  spec_edge),
@@ -964,7 +968,7 @@ class BFSEngine:
     def trace_count(self) -> int:
         return self._trace_count
 
-    def _build_kernel_expand(self):
+    def _build_kernel_expand(self, interpret: bool):
         """Pallas bsr_spmm frontier expansion, per shard.
 
         Each device's 128x128-blocked *transposed* adjacency slice
@@ -1002,9 +1006,10 @@ class BFSEngine:
             if packed:
                 return spmm_ops.frontier_expand_packed(
                     kb, kbr, kbc, f, n_rows_pad=row_pad, n_valid=n,
-                    n_blocks=p)
+                    n_blocks=p, interpret=interpret)
             cand = spmm_ops.frontier_expand(kb, kbr, kbc, f,
-                                            n_rows_pad=row_pad)
+                                            n_rows_pad=row_pad,
+                                            interpret=interpret)
             return cand[:n]
 
         return expand_fn, packed, host_arrays

@@ -17,28 +17,40 @@ INF = 2 ** 30
 
 
 def bfs_reference(src: np.ndarray, dst: np.ndarray, n: int, sources) -> np.ndarray:
-    """Level-synchronous serial BFS. Returns (n, S) int32 distances."""
+    """Level-synchronous serial BFS. Returns (n, S) int32 distances.
+
+    Vectorized per level over a CSR build: the frontier's adjacency
+    slices are gathered in one ``np.repeat`` over ``indptr``, filtered to
+    unvisited vertices and deduplicated into the next frontier, so a
+    Graph500 scale-20 graph checks in seconds per source.
+    """
     sources = np.atleast_1d(np.asarray(sources, dtype=np.int64))
     # CSR build
+    src = np.asarray(src, dtype=np.int64)
     order = np.argsort(src, kind="stable")
-    src_s, dst_s = np.asarray(src)[order], np.asarray(dst)[order]
+    dst_s = np.asarray(dst, dtype=np.int64)[order]
     indptr = np.zeros(n + 1, dtype=np.int64)
-    np.cumsum(np.bincount(src_s, minlength=n), out=indptr[1:])
+    np.cumsum(np.bincount(src, minlength=n), out=indptr[1:])
 
     out = np.full((n, sources.shape[0]), INF, dtype=np.int32)
     for j, s0 in enumerate(sources):
         dist = out[:, j]
         dist[s0] = 0
-        frontier = [int(s0)]
+        frontier = np.array([s0], dtype=np.int64)
         level = 1
-        while frontier:
-            nxt = []
-            for u in frontier:
-                for v in dst_s[indptr[u]:indptr[u + 1]]:
-                    if dist[v] == INF:
-                        dist[v] = level
-                        nxt.append(int(v))
-            frontier = nxt
+        while frontier.size:
+            starts = indptr[frontier]
+            counts = indptr[frontier + 1] - starts
+            total = int(counts.sum())
+            if total == 0:
+                break
+            # edge slot of every (frontier vertex, neighbor) pair: each
+            # vertex's slice start, advanced by the pair's rank in it
+            first = np.cumsum(counts) - counts
+            slots = np.repeat(starts - first, counts) + np.arange(total)
+            nbrs = dst_s[slots]
+            frontier = np.unique(nbrs[dist[nbrs] == INF])
+            dist[frontier] = level
             level += 1
     return out
 

@@ -30,8 +30,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.compat import tpu_compiler_params
-
 DEFAULT_BLOCK = 128
 
 
@@ -59,7 +57,7 @@ def _spmm_kernel(br_ref, bc_ref, blocks_ref, x_ref, y_ref):
 def bsr_spmm(blocks: jnp.ndarray, block_rows: jnp.ndarray,
              block_cols: jnp.ndarray, x: jnp.ndarray, *, n_rows_pad: int,
              block: int = DEFAULT_BLOCK, d_tile: int = DEFAULT_BLOCK,
-             interpret: bool = True) -> jnp.ndarray:
+             interpret: bool = False) -> jnp.ndarray:
     """Y = A @ X with A in block-CSR (blocks sorted by block_rows).
 
     blocks: (K, B, B) tile values; block_rows/block_cols: (K,) int32;
@@ -91,24 +89,34 @@ def bsr_spmm(blocks: jnp.ndarray, block_rows: jnp.ndarray,
         ),
         out_shape=jax.ShapeDtypeStruct((n_rows_pad, d_pad), jnp.float32),
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        name="bsr_spmm",
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(block_rows, block_cols, blocks, x)
     return out[:, :d]
 
 
+# word rows per bitpack grid step (Mosaic's second-minor block dimension
+# must be divisible by 8): 8 output word rows from 256 mask rows
+PACK_ROWS = 8
+
+
 def _bitpack_kernel(x_ref, out_ref):
-    """One grid step: fold a (32, S) 0/1 tile into one (1, S) uint32 word
-    row — bit ``i`` of the word is row ``i`` of the tile (LSB-first, the
-    ``frontier.pack_bits`` layout)."""
-    bits = (x_ref[...] > 0).astype(jnp.uint32)
-    shifts = jnp.arange(32, dtype=jnp.uint32)
-    out_ref[...] = (bits << shifts[:, None]).sum(
-        axis=0, dtype=jnp.uint32)[None, :]
+    """One grid step: fold a (256, S) 0/1 tile into 8 (1, S) uint32 word
+    rows — bit ``i`` of word row ``r`` is tile row ``32*r + i``
+    (LSB-first, the ``frontier.pack_bits`` layout).  Sums in int32 and
+    bit-casts back: Mosaic has no unsigned reduction, and the shifted
+    bits are disjoint, so the sum is their OR."""
+    shifts = jax.lax.broadcasted_iota(jnp.uint32, (32, 1), 0)
+    for r in range(PACK_ROWS):
+        bits = (x_ref[pl.ds(r * 32, 32), :] > 0).astype(jnp.uint32)
+        packed = (bits << shifts).astype(jnp.int32)
+        out_ref[pl.ds(r, 1), :] = jax.lax.bitcast_convert_type(
+            packed.sum(axis=0, keepdims=True), jnp.uint32)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def bitpack_words(mask: jnp.ndarray, *, interpret: bool = True) -> jnp.ndarray:
+def bitpack_words(mask: jnp.ndarray, *, interpret: bool = False) -> jnp.ndarray:
     """Pack a ``(32*W, S)`` candidate mask into ``(W, S)`` uint32 words on
     device — the packed-wire emission of the kernel expansion path.
 
@@ -119,13 +127,18 @@ def bitpack_words(mask: jnp.ndarray, *, interpret: bool = True) -> jnp.ndarray:
     m, s = mask.shape
     assert m % 32 == 0, m
     w = m // 32
-    return pl.pallas_call(
+    w8 = -(-w // PACK_ROWS) * PACK_ROWS
+    if w8 != w:
+        mask = jnp.pad(mask, ((0, (w8 - w) * 32), (0, 0)))
+    out = pl.pallas_call(
         _bitpack_kernel,
-        grid=(w,),
-        in_specs=[pl.BlockSpec((32, s), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, s), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((w, s), jnp.uint32),
+        grid=(w8 // PACK_ROWS,),
+        in_specs=[pl.BlockSpec((PACK_ROWS * 32, s), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((PACK_ROWS, s), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((w8, s), jnp.uint32),
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        name="bitpack_words",
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
     )(mask)
+    return out[:w]

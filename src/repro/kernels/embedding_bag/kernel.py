@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.compat import tpu_compiler_params
-
 
 def _bag_kernel(idx_ref, table_ref, out_ref):
     b = pl.program_id(0)
@@ -58,7 +56,7 @@ def embedding_bag_sum(indices: jnp.ndarray, table: jnp.ndarray, *,
         ),
         out_shape=jax.ShapeDtypeStruct((bsz, d), jnp.float32),
         interpret=interpret,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(indices, table.astype(jnp.float32))
     return out.astype(table.dtype)

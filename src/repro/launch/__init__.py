@@ -1,8 +1,10 @@
 """Launchers and host-topology helpers.
 
-This package ``__init__`` must stay import-light (stdlib only): the
-``host_devices`` helper has to run *before* JAX is first imported, and the
-launcher modules themselves import JAX at top level.
+This package ``__init__`` must stay import-light (stdlib only at import):
+the ``host_devices`` helper has to run *before* JAX is first imported, and
+the launcher modules themselves import JAX at top level.  The helpers
+that need JAX (``launch_devices``, ``use_compile_cache``) import it when
+called.
 """
 
 from __future__ import annotations
@@ -11,6 +13,10 @@ import os
 import sys
 
 _DEV_FLAG = "--xla_force_host_platform_device_count"
+
+# repository checkout root (src/repro/launch/__init__.py -> three up)
+CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))))
 
 
 def host_devices(n) -> None:
@@ -40,6 +46,45 @@ def host_devices(n) -> None:
             "already fixed its device count. Call it before any jax "
             "import (or set XLA_FLAGS in the environment).")
     os.environ["XLA_FLAGS"] = new
+
+
+def launch_devices(n: int = 0) -> list:
+    """The devices a launcher meshes for ``--devices n``.
+
+    ``n <= 0`` takes every visible device.  Otherwise exactly the first
+    ``n``: on the CPU ``host_devices(n)`` forced that many before JAX
+    started; on an accelerator host the machine fixes the count, and a
+    request for more devices than are visible exits with an error
+    instead of silently running on fewer shards.
+    """
+    import jax
+
+    devs = jax.devices()
+    if n <= 0:
+        return devs
+    if len(devs) < n:
+        raise SystemExit(
+            f"--devices {n}: only {len(devs)} {devs[0].platform} "
+            f"device(s) are visible; pass --devices {len(devs)} or fewer")
+    return devs[:n]
+
+
+def use_compile_cache() -> str:
+    """Point JAX's persistent compilation cache at a stable directory.
+
+    When ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and
+    this sets nothing.  Otherwise the cache goes to ``.jax_cache`` in the
+    checkout: a fixed path, so a later run of the same program finds
+    what an earlier one compiled.  Call it from an entry point's
+    ``main()``, never at import.  Returns the directory in use.
+    """
+    import jax
+
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = os.path.join(CHECKOUT, ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def parse_graph_spec(spec: str, default_n: int):

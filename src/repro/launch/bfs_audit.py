@@ -19,7 +19,8 @@ their reasons in the report but do not gate).  ``--out`` writes the
 full machine-readable ledger (``BENCH_audit.json`` in CI).
 """
 
-from repro.launch import host_devices_from_argv, parse_graph_spec
+from repro.launch import (host_devices_from_argv, launch_devices,
+                          parse_graph_spec, use_compile_cache)
 
 host_devices_from_argv()  # must precede the jax import below
 
@@ -29,7 +30,6 @@ import sys  # noqa: E402
 import time  # noqa: E402
 
 import numpy as np  # noqa: E402
-import jax  # noqa: E402
 from jax.sharding import Mesh  # noqa: E402
 
 from repro.analysis import hlo_audit  # noqa: E402
@@ -105,8 +105,9 @@ def main(argv=None):
         lo, hi = (float(x) for x in args.tolerance.split(","))
         tol = (lo, hi)
 
+    use_compile_cache()
     _, kind, n, spec_grid = parse_graph_spec(args.graph, 4096)
-    devs = jax.devices()
+    devs = launch_devices(args.devices)
     p = len(devs)
     grid = spec_grid
     if grid is None:
@@ -120,7 +121,7 @@ def main(argv=None):
     mesh_1d = Mesh(np.asarray(devs).reshape(p), ("p",))
     g1 = shard_graph(src, dst, n, p)
     g2 = shard_graph_2d(src, dst, n, r, c) if p > 1 else None
-    mesh_2d = make_grid_mesh(r, c) if p > 1 else None
+    mesh_2d = make_grid_mesh(r, c, devices=devs) if p > 1 else None
 
     cache = default_engine_cache()
     reports = []

@@ -31,7 +31,8 @@ CI): the fault plan's firing counts next to the outcome histogram,
 breaker trajectories and recovery latencies, and the watchdog snapshot.
 """
 
-from repro.launch import host_devices_from_argv
+from repro.launch import (host_devices_from_argv, launch_devices,
+                          use_compile_cache)
 
 host_devices_from_argv()  # must precede the jax import below
 
@@ -45,7 +46,6 @@ import urllib.error  # noqa: E402
 import urllib.request  # noqa: E402
 
 import numpy as np  # noqa: E402
-import jax  # noqa: E402
 from jax.sharding import Mesh  # noqa: E402
 
 from repro.core import BFSOptions  # noqa: E402
@@ -141,8 +141,9 @@ def main(argv=None) -> int:
                     help="write the chaos ledger json (BENCH_chaos)")
     ap.add_argument("--devices", type=int, default=0)  # parsed above
     args = ap.parse_args(argv)
+    use_compile_cache()
 
-    devs = jax.devices()
+    devs = launch_devices(args.devices)
     p = len(devs)
     mesh = Mesh(np.asarray(devs).reshape(p), ("p",))
     print(f"chaos: seed={args.seed} secs={args.secs:g} p={p} "

@@ -5,12 +5,14 @@
     PYTHONPATH=src python -m repro.launch.bfs_run \
         --graph erdos_renyi:100000 --graph star:50000 --repeats 2
 
-Uses every visible device as one 1-D shard row (on a TPU pod slice this is
-the full production run; on CPU it is p=1), or — with ``--partition 2d``
-— as an ``r x c`` grid (``--grid 2x2``; defaults to the most-square
-factorization) running the two-phase edge-partitioned engine.
-``--devices N`` forces N host devices for a local multi-shard run
-(applied before jax initializes via ``repro.launch.host_devices``).
+Uses every visible device (or the first ``--devices N``) as one 1-D
+shard row, or — with ``--partition 2d`` — as an ``r x c`` grid
+(``--grid 2x2``; defaults to the most-square factorization) running the
+two-phase edge-partitioned engine.
+On the CPU ``--devices N`` forces N host devices for a local multi-shard
+run (applied before jax initializes via ``repro.launch.host_devices``);
+on a TPU host it meshes the first N chips, and exits when fewer are
+visible.
 
 The launcher drives the compile-once lifecycle: one ``plan().compile()``
 per (graph, options, mesh), then ``--repeats`` traversals from rotating
@@ -22,7 +24,8 @@ stats line shows the cross-graph compile amortization (hits / misses /
 evictions / compile seconds).
 """
 
-from repro.launch import host_devices_from_argv, parse_graph_spec
+from repro.launch import (host_devices_from_argv, launch_devices,
+                          parse_graph_spec, use_compile_cache)
 
 host_devices_from_argv()  # must precede the jax import below
 
@@ -31,7 +34,6 @@ import contextlib  # noqa: E402
 import time  # noqa: E402
 
 import numpy as np  # noqa: E402
-import jax  # noqa: E402
 from jax.sharding import Mesh  # noqa: E402
 
 from repro.analysis import trace_model  # noqa: E402
@@ -43,6 +45,7 @@ from repro.serve.engine_cache import default_engine_cache  # noqa: E402
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--workload", default=None,
                     choices=[w.name for w in BFS_WORKLOADS])
@@ -115,7 +118,7 @@ def main():
     else:
         graphs = [("erdos_renyi", args.n, {})]
 
-    devs = jax.devices()
+    devs = launch_devices(args.devices)
     p = len(devs)
     sieve = {"auto": "auto", "on": True, "off": False}[args.sieve]
     if args.partition == "2d":
@@ -123,7 +126,7 @@ def main():
             r, c = (int(x) for x in args.grid.lower().split("x"))
         else:
             r, c = default_grid(p)
-        mesh = make_grid_mesh(r, c)
+        mesh = make_grid_mesh(r, c, devices=devs)
         axis = None                          # plan uses the mesh's two axes
         # --exchange names a *dense* (1-D) strategy; the 2-D phases use
         # expand/fold strategies.  Honor it when it is also a registered

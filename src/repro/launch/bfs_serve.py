@@ -46,7 +46,8 @@ deadline on requests that carry none; ``/readyz`` reports readiness
 separately from ``/healthz`` liveness.
 """
 
-from repro.launch import host_devices_from_argv, parse_graph_spec
+from repro.launch import (host_devices_from_argv, launch_devices,
+                          parse_graph_spec, use_compile_cache)
 
 host_devices_from_argv()  # must precede the jax import below
 
@@ -56,7 +57,6 @@ import sys  # noqa: E402
 import time  # noqa: E402
 
 import numpy as np  # noqa: E402
-import jax  # noqa: E402
 from jax.sharding import Mesh  # noqa: E402
 
 from repro.analysis import trace_model  # noqa: E402
@@ -154,6 +154,7 @@ def _serve_http(args, svc, graph_specs):
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--workload", default=None,
                     choices=[w.name for w in BFS_WORKLOADS])
@@ -252,7 +253,7 @@ def main():
         specs = [("default", "erdos_renyi", args.n, None,
                   dict(_GEN_DEFAULTS["erdos_renyi"]))]
 
-    devs = jax.devices()
+    devs = launch_devices(args.devices)
     p = len(devs)
     mesh_1d = Mesh(np.asarray(devs).reshape(p), ("p",))
 
@@ -278,8 +279,8 @@ def main():
                              "gen_kwargs": kw}
         g = shard_graph(src, dst, n, p)
         if grid:
-            svc.add_graph(name, g, mesh=make_grid_mesh(*grid), axis=None,
-                          partition="2d")
+            svc.add_graph(name, g, mesh=make_grid_mesh(*grid, devices=devs),
+                          axis=None, partition="2d")
         else:
             svc.add_graph(name, g)
         part_lbl = f"2d:{grid[0]}x{grid[1]}" if grid else "1d"

@@ -66,17 +66,19 @@ def default_grid(p: int) -> tuple:
     return r, p // r
 
 
-def make_grid_mesh(r: int = 2, c: int = 2, names: tuple = ("rows", "cols")):
+def make_grid_mesh(r: int = 2, c: int = 2, names: tuple = ("rows", "cols"),
+                   devices=None):
     """``r x c`` device grid for the 2-D BFS edge partition.
 
     Device ``(i, j)`` owns vertex chunk ``i*c + j``; the expand phase
     allgathers frontiers over ``names[1]`` (within a grid row) and the
     fold phase merges candidates over ``names[0]`` (within a grid
     column).  Needs ``r*c`` local devices (``host_devices(n)`` /
-    ``--devices n`` before the first jax import for CPU runs).
+    ``--devices n`` before the first jax import for CPU runs); takes the
+    first ``r*c`` of ``devices`` (default: every visible device).
     """
     import numpy as np
-    devs = jax.devices()
+    devs = jax.devices() if devices is None else list(devices)
     if len(devs) < r * c:
         raise ValueError(f"grid {r}x{c} needs {r*c} devices; "
                          f"have {len(devs)}")

@@ -32,7 +32,6 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import GNNConfig
-from repro.core.compat import shard_map
 from repro.core.partition import Partition1D
 from repro.models.gnn import common as C
 from repro.models.gnn.models import graphcast_init
@@ -178,7 +177,7 @@ def make_loss_fn(cfg: GNNConfig, mesh, axis):
     def loss_fn(params, batch):
         param_specs = jax.tree.map(lambda _: P(), params)
         fn = functools.partial(_shard_forward, cfg=cfg, axis=axis)
-        mapped = shard_map(
+        mapped = jax.shard_map(
             fn, mesh=mesh,
             in_specs=(param_specs, {
                 "node_feats": P(axis, None),

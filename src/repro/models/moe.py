@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import MoEConfig
-from repro.core.compat import shard_map
 from repro.layers.core import swiglu
 from repro.models import sharding_hints as hints
 
@@ -207,7 +206,7 @@ def moe_apply_sharded(params, x: jnp.ndarray, cfg: MoEConfig):
                             "w_down": P(None, None)}
     fn = functools.partial(_moe_local_experts, cfg=cfg, e_local=e_local,
                            model_axis=model, dp_axes=dp)
-    out, lb, dropped = shard_map(
+    out, lb, dropped = jax.shard_map(
         fn, mesh=mesh,
         in_specs=(pspecs, P(dp, None)),
         out_specs=(P(dp, None), P(), P()),

@@ -25,13 +25,12 @@ from jax.sharding import Mesh, PartitionSpec as P  # noqa: E402
 
 from repro.core import exchange as ex  # noqa: E402
 from repro.core import frontier as fr  # noqa: E402
-from repro.core.compat import shard_map  # noqa: E402
 from repro.launch.hlo_stats import collective_bytes  # noqa: E402
 
 
 def compile_and_parse(fn, in_specs, out_specs, arg_shapes, mesh):
-    mapped = shard_map(fn, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_vma=False)
+    mapped = jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False)
     lowered = jax.jit(mapped).lower(*arg_shapes)
     return collective_bytes(lowered.compile().as_text())
 

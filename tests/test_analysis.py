@@ -374,6 +374,7 @@ def test_frontend_stats_loop_exits_promptly_on_shutdown():
 def test_audit_known_bad_fixture_multidev():
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
+    env["JAX_PLATFORMS"] = "cpu"   # forced host devices; never the chip
     r = subprocess.run(
         [sys.executable, os.path.join(REPO, "tests", "helpers",
                                       "audit_bad.py")],

@@ -34,6 +34,46 @@ def test_bfs_p1_matches_reference(kind, kw, mode):
     assert stats.visited == int((want < INF).sum())
 
 
+def _loop_bfs_oracle(src, dst, n, sources):
+    """Per-edge Python loop BFS: the plain oracle the vectorized
+    ``bfs_reference`` must agree with exactly."""
+    adj = [[] for _ in range(n)]
+    for u, v in zip(np.asarray(src).tolist(), np.asarray(dst).tolist()):
+        adj[u].append(v)
+    out = np.full((n, len(sources)), INF, dtype=np.int32)
+    for j, s0 in enumerate(sources):
+        dist = out[:, j]
+        dist[s0] = 0
+        frontier, level = [s0], 1
+        while frontier:
+            nxt = []
+            for u in frontier:
+                for v in adj[u]:
+                    if dist[v] == INF:
+                        dist[v] = level
+                        nxt.append(v)
+            frontier, level = nxt, level + 1
+    return out
+
+
+@pytest.mark.parametrize("kind,n,kw", [
+    ("star", 300, {}),
+    ("erdos_renyi", 600, dict(avg_degree=3)),     # several components
+    ("small_world", 500, dict(k=4, beta=0.1)),     # deep traversal
+    ("rmat", 512, dict(edge_factor=4)),            # isolated vertices
+])
+def test_vectorized_reference_matches_loop_oracle(kind, n, kw):
+    src, dst = generate(kind, n, seed=5, **kw)
+    sources = [0, 7, n - 1]
+    np.testing.assert_array_equal(bfs_reference(src, dst, n, sources),
+                                  _loop_bfs_oracle(src, dst, n, sources))
+    # directed: one orientation only, so reachability is asymmetric
+    half = src.shape[0] // 2
+    np.testing.assert_array_equal(
+        bfs_reference(src[:half], dst[:half], n, sources),
+        _loop_bfs_oracle(src[:half], dst[:half], n, sources))
+
+
 def test_bfs_batched_sources_p1():
     n = 500
     src, dst = generate("erdos_renyi", n, seed=2, avg_degree=5)
@@ -113,6 +153,7 @@ def test_multidevice_bfs_subprocess():
     """Full 8-device matrix: strategies x modes x graph families."""
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
+    env["JAX_PLATFORMS"] = "cpu"   # forced host devices; never the chip
     r = subprocess.run(
         [sys.executable, os.path.join(REPO, "tests", "helpers", "multidev_bfs.py")],
         env=env, capture_output=True, text=True, timeout=900)

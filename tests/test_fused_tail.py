@@ -60,7 +60,7 @@ def test_fold_update_matches_reference(m, s, use_pallas):
                     rng.integers(0, 5, (m, s)).astype(np.int32))
     want = _ref_fold_update(words, dist, 7)
     got = fold_update(jnp.asarray(words), jnp.asarray(dist), 7,
-                      use_pallas=use_pallas)
+                      use_pallas=use_pallas, interpret=use_pallas)
     for g, w in zip(got, want):
         np.testing.assert_array_equal(np.asarray(g), w)
 

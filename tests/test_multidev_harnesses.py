@@ -11,6 +11,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def _run(script):
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
+    env["JAX_PLATFORMS"] = "cpu"   # forced host devices; never the chip
     r = subprocess.run(
         [sys.executable, os.path.join(REPO, "tests", "helpers", script)],
         env=env, capture_output=True, text=True, timeout=900)
