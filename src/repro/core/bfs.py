@@ -63,6 +63,21 @@ if TYPE_CHECKING:  # graphs.formats imports core.partition; avoid the cycle
 
 INF = fr.INF
 
+# The traversal's own names in the compiled program.  ``jax.named_scope``
+# writes them into each HLO instruction's ``op_name`` metadata and
+# changes no computation.  Every level runs under one mode scope (in the
+# order of ``BFSRunStats.mode_counts``) and each of its ops under one phase
+# scope; where scopes nest, the outermost mode and the innermost phase
+# count.  A queue level that escalates runs its dense ops inside
+# ``bfs.queue``, as ``mode_counts`` counts it.
+MODE_SCOPES = ("bfs.dense", "bfs.queue", "bfs.bottom_up")
+PHASE_SCOPES = ("bfs.decide", "bfs.expand", "bfs.exchange", "bfs.fold",
+                "bfs.update")
+INIT_SCOPE = "bfs.init"
+_DENSE, _QUEUE, _BOTTOM_UP = MODE_SCOPES
+_DECIDE, _EXPAND, _EXCHANGE, _FOLD, _UPDATE = PHASE_SCOPES
+_scope = jax.named_scope
+
 
 @dataclasses.dataclass(frozen=True)
 class BFSOptions:
@@ -262,29 +277,35 @@ def _make_shard_fn(part: Partition1D, e_total: int, s: int,
                                               wire=bottom_up_wire)
 
     def dense_level(frontier, dist, level, src_local, dst_global, kargs):
-        if expand_fn is not None:
-            cand = expand_fn(frontier, *kargs)
-        else:
-            cand = fr.expand_dense(frontier, src_local, dst_global, n)
+        with _scope(_EXPAND):
+            if expand_fn is not None:
+                cand = expand_fn(frontier, *kargs)
+            else:
+                cand = fr.expand_dense(frontier, src_local, dst_global, n)
         if dense_strategy.wire == "packed":
             # keep candidates packed through the collective: pack once
             # (unless the kernel already emitted words), OR-merge on the
             # wire payload, unpack only the owned W-word slice
-            words = cand if (expand_fn is not None and expand_emits_packed
-                             ) else fr.pack_bits(cand, n_blocks=p)
-            merged = dense_strategy.impl(words, axis)
+            with _scope(_EXCHANGE):
+                words = cand if (expand_fn is not None and expand_emits_packed
+                                 ) else fr.pack_bits(cand, n_blocks=p)
+                merged = dense_strategy.impl(words, axis)
             if fused:
                 # fused tail: one kernel pass bit-tests the merged words
                 # against dist, writes depths and emits the next packed
                 # frontier generation — no (shard, S) unpack between the
                 # collective and the next level
-                dist, new, nwords = fold_kernel.fold_update(
-                    merged, dist, level, use_pallas=on_tpu)
+                with _scope(_FOLD):
+                    dist, new, nwords = fold_kernel.fold_update(
+                        merged, dist, level, use_pallas=on_tpu)
                 return dist, new, nwords, jnp.float32(dense_bytes)
-            own = fr.unpack_bits(merged, shard)
+            with _scope(_FOLD):
+                own = fr.unpack_bits(merged, shard)
         else:
-            own = dense_strategy.impl(cand, axis)
-        dist, new = _owned_update(dist, own, level)
+            with _scope(_EXCHANGE):
+                own = dense_strategy.impl(cand, axis)
+        with _scope(_UPDATE):
+            dist, new = _owned_update(dist, own, level)
         return dist, new, None, jnp.float32(dense_bytes)
 
     def bottom_up_level(frontier, fwords, dist, level, in_src_global,
@@ -295,68 +316,84 @@ def _make_shard_fn(part: Partition1D, e_total: int, s: int,
             # plans carry the packed generation in loop state (the
             # previous level's tail emitted it), so the gather payload is
             # ready with no pack on this level's critical path.
-            fw = fwords if fused else fr.pack_bits(frontier)   # (W, S)
-            fglob_w = ex.allgather_frontier(fw, axis)          # (p*W, S)
-            cand = fr.expand_bottom_up_packed(fglob_w, in_src_global,
-                                              in_dst_local, shard, w_shard)
+            with _scope(_EXCHANGE):
+                fw = fwords if fused else fr.pack_bits(frontier)  # (W, S)
+                fglob_w = ex.allgather_frontier(fw, axis)         # (p*W, S)
+            with _scope(_EXPAND):
+                cand = fr.expand_bottom_up_packed(
+                    fglob_w, in_src_global, in_dst_local, shard, w_shard)
         else:
-            fglob = ex.allgather_frontier(frontier, axis)  # (n, S)
-            cand = fr.expand_bottom_up(fglob, in_src_global, in_dst_local,
-                                       shard)
-        dist, new = _owned_update(dist, cand, level)
-        nwords = fr.pack_bits(new) if fused else None
+            with _scope(_EXCHANGE):
+                fglob = ex.allgather_frontier(frontier, axis)  # (n, S)
+            with _scope(_EXPAND):
+                cand = fr.expand_bottom_up(fglob, in_src_global,
+                                           in_dst_local, shard)
+        with _scope(_UPDATE):
+            dist, new = _owned_update(dist, cand, level)
+            nwords = fr.pack_bits(new) if fused else None
         return dist, new, nwords, jnp.float32(bottom_up_bytes)
 
     def queue_level(frontier, dist, level, src_local, dst_global, kargs):
-        me = lax.axis_index(axis)
-        valid = dst_global >= 0
-        active = (frontier[src_local, 0] > 0) & valid
-        hits = jnp.int32(0)
-        if sieve:
-            # replicate each shard's coarse visited summary and drop
-            # candidates whose whole bucket is already visited — they
-            # can never lower a distance, so they need not ship
-            own_sum = fr.sieve_summary(dist[:, 0], sv_bits, sv_bucket)
-            gsum = lax.all_gather(own_sum, axis, tiled=True)  # (p*words,)
-            drop = fr.sieve_lookup(gsum, dst_global, shard, sv_bits,
-                                   sv_bucket, sv_words) & active
-            hits = lax.psum(drop.sum(dtype=jnp.int32), axis)
-            active = active & ~drop
-        buckets, local_mask, _, overflow = fr.build_queue_buckets(
-            dst_global, active, part, me, opts.queue_cap,
-            local_update=opts.local_update, dedupe=opts.dedupe)
-        if use_compressed:
-            base = jnp.arange(p, dtype=jnp.int32)[:, None] * shard
-            rel = jnp.where(buckets >= 0, buckets - base, -1)
-            payload, enc_ovf = jax.vmap(
-                lambda row: fr.encode_delta_varint(row, q_byte_cap, shard)
-            )(rel)
-            overflow = overflow | enc_ovf.any()
+        with _scope(_EXPAND):
+            me = lax.axis_index(axis)
+            valid = dst_global >= 0
+            active = (frontier[src_local, 0] > 0) & valid
+            hits = jnp.int32(0)
+            if sieve:
+                # replicate each shard's coarse visited summary and drop
+                # candidates whose whole bucket is already visited — they
+                # can never lower a distance, so they need not ship
+                own_sum = fr.sieve_summary(dist[:, 0], sv_bits, sv_bucket)
+                with _scope(_EXCHANGE):
+                    gsum = lax.all_gather(own_sum, axis, tiled=True)
+                drop = fr.sieve_lookup(gsum, dst_global, shard, sv_bits,
+                                       sv_bucket, sv_words) & active
+                with _scope(_EXCHANGE):
+                    hits = lax.psum(drop.sum(dtype=jnp.int32), axis)
+                active = active & ~drop
+            buckets, local_mask, _, overflow = fr.build_queue_buckets(
+                dst_global, active, part, me, opts.queue_cap,
+                local_update=opts.local_update, dedupe=opts.dedupe)
+            if use_compressed:
+                base = jnp.arange(p, dtype=jnp.int32)[:, None] * shard
+                rel = jnp.where(buckets >= 0, buckets - base, -1)
+                payload, enc_ovf = jax.vmap(
+                    lambda row: fr.encode_delta_varint(row, q_byte_cap, shard)
+                )(rel)
+                overflow = overflow | enc_ovf.any()
         # Exactness guarantee: if any shard's bucket (or compressed
         # stream) overflowed, run the whole level densely instead (the
         # predicate is replicated, so all shards take the same branch and
         # collectives stay collective).
-        overflow_any = lax.psum(overflow.astype(jnp.int32), axis) > 0
+        with _scope(_EXCHANGE):
+            overflow_any = lax.psum(overflow.astype(jnp.int32), axis) > 0
 
         def sparse_branch():
-            if use_compressed:
-                recv = queue_strategy.impl(payload, axis)  # (p, byte_cap)
-                rec_ids = jax.vmap(
-                    lambda row: fr.decode_delta_varint(row, opts.queue_cap,
-                                                       shard))(recv)
-                rec_ids = jnp.where(rec_ids >= 0, rec_ids + me * shard, -1)
-            else:
-                rec_ids = queue_strategy.impl(buckets, axis)
-            own = jnp.maximum(fr.apply_queue(rec_ids, me, shard), local_mask)
-            d2, new = _owned_update(dist, own[:, None], level)
-            nwords = fr.pack_bits(new) if fused else None
+            with _scope(_EXCHANGE):
+                if use_compressed:
+                    recv = queue_strategy.impl(payload, axis)  # (p, byte_cap)
+                    rec_ids = jax.vmap(
+                        lambda row: fr.decode_delta_varint(
+                            row, opts.queue_cap, shard))(recv)
+                    rec_ids = jnp.where(rec_ids >= 0, rec_ids + me * shard,
+                                        -1)
+                else:
+                    rec_ids = queue_strategy.impl(buckets, axis)
+            with _scope(_FOLD):
+                own = jnp.maximum(fr.apply_queue(rec_ids, me, shard),
+                                  local_mask)
+            with _scope(_UPDATE):
+                d2, new = _owned_update(dist, own[:, None], level)
+                nwords = fr.pack_bits(new) if fused else None
             return d2, new, nwords, jnp.float32(queue_bytes)
 
         def dense_branch():
             d2, new, nwords, bb = dense_level(frontier, dist, level,
                                               src_local, dst_global, kargs)
             # the sieve gather (if any) already ran before escalation
-            return d2, new, nwords, bb + jnp.float32(sieve_gather_bytes)
+            with _scope(_UPDATE):
+                bb = bb + jnp.float32(sieve_gather_bytes)
+            return d2, new, nwords, bb
 
         d2, new, nwords, bytes_ = lax.cond(overflow_any, dense_branch,
                                            sparse_branch)
@@ -374,38 +411,46 @@ def _make_shard_fn(part: Partition1D, e_total: int, s: int,
         hits = jnp.int32(0)
 
         if opts.mode == "dense":
-            dist, new, nwords, b = dense_level(frontier, dist, level,
-                                               src_local, dst_global, kargs)
-            modes = modes.at[0].add(1)
+            with _scope(_DENSE):
+                dist, new, nwords, b = dense_level(
+                    frontier, dist, level, src_local, dst_global, kargs)
+            with _scope(_UPDATE):
+                modes = modes.at[0].add(1)
             ovf = jnp.bool_(False)
         elif opts.mode == "queue":
-            dist, new, nwords, b, ovf, hits = queue_level(
-                frontier, dist, level, src_local, dst_global, kargs)
-            modes = modes.at[1].add(1)
+            with _scope(_QUEUE):
+                dist, new, nwords, b, ovf, hits = queue_level(
+                    frontier, dist, level, src_local, dst_global, kargs)
+            with _scope(_UPDATE):
+                modes = modes.at[1].add(1)
         else:  # auto: direction-optimizing hybrid
-            f_verts = lax.psum(frontier.sum(dtype=jnp.int32), axis)
-            f_edges_local = jnp.where(
-                dst_global >= 0, frontier[src_local, 0], 0).sum(dtype=jnp.int32)
-            f_edges = lax.psum(f_edges_local, axis)
-            big = f_verts > jnp.int32(bottom_up_cutoff)
-            tiny = f_edges < jnp.int32(queue_edge_cutoff)
+            with _scope(_DECIDE):
+                f_verts = lax.psum(frontier.sum(dtype=jnp.int32), axis)
+                f_edges_local = jnp.where(
+                    dst_global >= 0, frontier[src_local, 0], 0
+                ).sum(dtype=jnp.int32)
+                f_edges = lax.psum(f_edges_local, axis)
+                big = f_verts > jnp.int32(bottom_up_cutoff)
+                tiny = f_edges < jnp.int32(queue_edge_cutoff)
 
             def do_bottom_up():
-                d, nw, nwd, b = bottom_up_level(frontier, fwords, dist,
-                                                level, in_src_global,
-                                                in_dst_local)
+                with _scope(_BOTTOM_UP):
+                    d, nw, nwd, b = bottom_up_level(frontier, fwords, dist,
+                                                    level, in_src_global,
+                                                    in_dst_local)
                 return (d, nw, nwd, b, jnp.bool_(False), jnp.int32(2),
                         jnp.int32(0))
 
             def do_queue():
-                d, nw, nwd, b, ovf, h = queue_level(frontier, dist, level,
-                                                    src_local, dst_global,
-                                                    kargs)
+                with _scope(_QUEUE):
+                    d, nw, nwd, b, ovf, h = queue_level(
+                        frontier, dist, level, src_local, dst_global, kargs)
                 return d, nw, nwd, b, ovf, jnp.int32(1), h
 
             def do_dense():
-                d, nw, nwd, b = dense_level(frontier, dist, level,
-                                            src_local, dst_global, kargs)
+                with _scope(_DENSE):
+                    d, nw, nwd, b = dense_level(frontier, dist, level,
+                                                src_local, dst_global, kargs)
                 return (d, nw, nwd, b, jnp.bool_(False), jnp.int32(0),
                         jnp.int32(0))
 
@@ -416,20 +461,22 @@ def _make_shard_fn(part: Partition1D, e_total: int, s: int,
             else:
                 dist, new, nwords, b, ovf, which, hits = lax.cond(
                     big, do_bottom_up, do_dense)
-            modes = modes.at[which].add(1)
+            with _scope(_UPDATE):
+                modes = modes.at[which].add(1)
 
-        # Mask padding vertices (ids >= n_logical can never be visited).
-        new = new * valid_local[:, None].astype(new.dtype)
-        dist = jnp.where(valid_local[:, None], dist, INF)
-        active = lax.psum(new.sum(dtype=jnp.int32), axis) > 0
-        if fused:
-            # next packed generation, pad bits cleared to match the masked
-            # byte frontier exactly
-            fwords = nwords & vwords
-            return (dist, new, fwords, level + 1, active, bytes_acc + b,
+        with _scope(_UPDATE):
+            # Mask padding vertices (ids >= n_logical can never be visited).
+            new = new * valid_local[:, None].astype(new.dtype)
+            dist = jnp.where(valid_local[:, None], dist, INF)
+            active = lax.psum(new.sum(dtype=jnp.int32), axis) > 0
+            if fused:
+                # next packed generation, pad bits cleared to match the
+                # masked byte frontier exactly
+                fwords = nwords & vwords
+                return (dist, new, fwords, level + 1, active, bytes_acc + b,
+                        overflowed | ovf, modes, hits_acc + hits)
+            return (dist, new, level + 1, active, bytes_acc + b,
                     overflowed | ovf, modes, hits_acc + hits)
-        return (dist, new, level + 1, active, bytes_acc + b,
-                overflowed | ovf, modes, hits_acc + hits)
 
     def shard_fn(src_local, dst_global, in_src_global, in_dst_local, *rest):
         if on_trace is not None:
@@ -551,31 +598,43 @@ def _make_shard_fn_2d(part2: Partition2D, e_total: int, s: int,
             # and read source bits straight from the gathered words; the
             # unfused path packs here and unpacks the c gathered segments
             # into the row frontier the expansion reads.
-            payload = fwords if fused else fr.pack_bits(frontier)
-            fw = expand_strategy.impl(payload, col_axis)
+            with _scope(_EXCHANGE):
+                payload = fwords if fused else fr.pack_bits(frontier)
+                fw = expand_strategy.impl(payload, col_axis)
             if fused:
-                cand = fr.expand_dense_2d_packed(fw, src_rowlocal,
-                                                 dst_fold, fold_len, b)
+                with _scope(_EXPAND):
+                    cand = fr.expand_dense_2d_packed(fw, src_rowlocal,
+                                                     dst_fold, fold_len, b)
             else:
-                frow = fr.unpack_bits(fw, b, n_blocks=c)         # (c*b, S)
+                with _scope(_EXCHANGE):
+                    frow = fr.unpack_bits(fw, b, n_blocks=c)     # (c*b, S)
+                with _scope(_EXPAND):
+                    cand = fr.expand_dense_2d(frow, src_rowlocal, dst_fold,
+                                              fold_len)
+        else:
+            with _scope(_EXCHANGE):
+                frow = expand_strategy.impl(frontier, col_axis)  # (c*b, S)
+            with _scope(_EXPAND):
                 cand = fr.expand_dense_2d(frow, src_rowlocal, dst_fold,
                                           fold_len)
-        else:
-            frow = expand_strategy.impl(frontier, col_axis)      # (c*b, S)
-            cand = fr.expand_dense_2d(frow, src_rowlocal, dst_fold,
-                                      fold_len)
         if fold_strategy.wire == "packed":
-            cw = fold_strategy.impl(fr.pack_bits(cand, n_blocks=r), row_axis)
+            with _scope(_EXCHANGE):
+                cw = fold_strategy.impl(fr.pack_bits(cand, n_blocks=r),
+                                        row_axis)
             if fused:
                 # fused fold tail: merge words -> dist depths + next
                 # packed generation in one kernel pass (no (b, S) unpack)
-                dist, new, nwords = fold_kernel.fold_update(
-                    cw, dist, level, use_pallas=on_tpu)
+                with _scope(_FOLD):
+                    dist, new, nwords = fold_kernel.fold_update(
+                        cw, dist, level, use_pallas=on_tpu)
                 return dist, new, nwords, dense_bytes
-            own = fr.unpack_bits(cw, b)                          # (b, S)
+            with _scope(_FOLD):
+                own = fr.unpack_bits(cw, b)                      # (b, S)
         else:
-            own = fold_strategy.impl(cand, row_axis)             # (b, S)
-        dist, new = _owned_update(dist, own, level)
+            with _scope(_EXCHANGE):
+                own = fold_strategy.impl(cand, row_axis)         # (b, S)
+        with _scope(_UPDATE):
+            dist, new = _owned_update(dist, own, level)
         return dist, new, None, dense_bytes
 
     def bottom_up_level(frontier, fwords, dist, level, in_src_global,
@@ -583,75 +642,93 @@ def _make_shard_fn_2d(part2: Partition2D, e_total: int, s: int,
         # gather over (rows, cols) is chunk-id order: chunk k lives on
         # grid device (k // c, k % c), the same major-first linearization
         if bottom_up_wire == "packed":
-            fw = fwords if fused else fr.pack_bits(frontier)     # (Wb, S)
-            fglob_w = ex.allgather_frontier(fw, grid_axes)       # (p*Wb, S)
-            cand = fr.expand_bottom_up_packed(fglob_w, in_src_global,
-                                              in_dst_local, b, w_chunk)
+            with _scope(_EXCHANGE):
+                fw = fwords if fused else fr.pack_bits(frontier)  # (Wb, S)
+                fglob_w = ex.allgather_frontier(fw, grid_axes)    # (p*Wb, S)
+            with _scope(_EXPAND):
+                cand = fr.expand_bottom_up_packed(fglob_w, in_src_global,
+                                                  in_dst_local, b, w_chunk)
         else:
-            fglob = ex.allgather_frontier(frontier, grid_axes)   # (n, S)
-            cand = fr.expand_bottom_up(fglob, in_src_global, in_dst_local, b)
-        dist, new = _owned_update(dist, cand, level)
-        nwords = fr.pack_bits(new) if fused else None
+            with _scope(_EXCHANGE):
+                fglob = ex.allgather_frontier(frontier, grid_axes)  # (n, S)
+            with _scope(_EXPAND):
+                cand = fr.expand_bottom_up(fglob, in_src_global,
+                                           in_dst_local, b)
+        with _scope(_UPDATE):
+            dist, new = _owned_update(dist, cand, level)
+            nwords = fr.pack_bits(new) if fused else None
         return dist, new, nwords, bottom_up_bytes
 
     def queue_level(frontier, fwords, dist, level, src_rowlocal, dst_fold):
-        me_row = lax.axis_index(row_axis)
-        ids, _, pack_ovf = fr.pack_frontier_ids(frontier, opts.queue_cap)
-        if use_comp_expand:
-            pay, enc_ovf = fr.encode_delta_varint(ids, g_byte_cap, b)
-            pack_ovf = pack_ovf | enc_ovf
-            all_pay = expand_sparse_strategy.impl(pay, col_axis)
-            all_ids = jax.vmap(
-                lambda seg: fr.decode_delta_varint(seg, opts.queue_cap, b)
-            )(all_pay.reshape(c, g_byte_cap)).reshape(-1)        # (c*cap,)
-        else:
-            all_ids = expand_sparse_strategy.impl(ids, col_axis)  # (c*cap,)
-        frow = fr.unpack_row_frontier(all_ids, c, b)             # (c*b, 1)
-        valid = dst_fold >= 0
-        active = (frow[src_rowlocal, 0] > 0) & valid
-        hits = jnp.int32(0)
-        if sieve:
-            # candidate dst_fold = rr*b + loc targets the vertex owned by
-            # the grid device (rr, me_col), global chunk rr*c + me_col —
-            # the both-axes summary gather is in exactly that chunk order
-            own_sum = fr.sieve_summary(dist[:, 0], sv_bits, sv_bucket)
-            gsum = lax.all_gather(own_sum, grid_axes, tiled=True)
-            me_col = lax.axis_index(col_axis)
-            df = jnp.where(active, dst_fold, 0)
-            rr = df // b
-            gid = (rr * c + me_col) * b + (df - rr * b)
-            drop = fr.sieve_lookup(gsum, gid, b, sv_bits, sv_bucket,
-                                   sv_words) & active
-            hits = lax.psum(drop.sum(dtype=jnp.int32), grid_axes)
-            active = active & ~drop
-        buckets, local_mask, _, bucket_ovf = fr.build_queue_buckets_2d(
-            dst_fold, active, part2, me_row, opts.queue_cap,
-            local_update=opts.local_update, dedupe=opts.dedupe)
-        if use_comp_fold:
-            base = jnp.arange(r, dtype=jnp.int32)[:, None] * b
-            rel = jnp.where(buckets >= 0, buckets - base, -1)
-            fpay, fenc_ovf = jax.vmap(
-                lambda row: fr.encode_delta_varint(row, g_byte_cap, b))(rel)
-            bucket_ovf = bucket_ovf | fenc_ovf.any()
+        # the row phase ships the frontier's ids, so packing, encoding
+        # and decoding them is exchange work
+        with _scope(_EXCHANGE):
+            me_row = lax.axis_index(row_axis)
+            ids, _, pack_ovf = fr.pack_frontier_ids(frontier, opts.queue_cap)
+            if use_comp_expand:
+                pay, enc_ovf = fr.encode_delta_varint(ids, g_byte_cap, b)
+                pack_ovf = pack_ovf | enc_ovf
+                all_pay = expand_sparse_strategy.impl(pay, col_axis)
+                all_ids = jax.vmap(
+                    lambda seg: fr.decode_delta_varint(seg, opts.queue_cap, b)
+                )(all_pay.reshape(c, g_byte_cap)).reshape(-1)    # (c*cap,)
+            else:
+                all_ids = expand_sparse_strategy.impl(ids, col_axis)
+            frow = fr.unpack_row_frontier(all_ids, c, b)         # (c*b, 1)
+        with _scope(_EXPAND):
+            valid = dst_fold >= 0
+            active = (frow[src_rowlocal, 0] > 0) & valid
+            hits = jnp.int32(0)
+            if sieve:
+                # candidate dst_fold = rr*b + loc targets the vertex owned
+                # by the grid device (rr, me_col), global chunk
+                # rr*c + me_col — the both-axes summary gather is in
+                # exactly that chunk order
+                own_sum = fr.sieve_summary(dist[:, 0], sv_bits, sv_bucket)
+                with _scope(_EXCHANGE):
+                    gsum = lax.all_gather(own_sum, grid_axes, tiled=True)
+                me_col = lax.axis_index(col_axis)
+                df = jnp.where(active, dst_fold, 0)
+                rr = df // b
+                gid = (rr * c + me_col) * b + (df - rr * b)
+                drop = fr.sieve_lookup(gsum, gid, b, sv_bits, sv_bucket,
+                                       sv_words) & active
+                with _scope(_EXCHANGE):
+                    hits = lax.psum(drop.sum(dtype=jnp.int32), grid_axes)
+                active = active & ~drop
+            buckets, local_mask, _, bucket_ovf = fr.build_queue_buckets_2d(
+                dst_fold, active, part2, me_row, opts.queue_cap,
+                local_update=opts.local_update, dedupe=opts.dedupe)
+            if use_comp_fold:
+                base = jnp.arange(r, dtype=jnp.int32)[:, None] * b
+                rel = jnp.where(buckets >= 0, buckets - base, -1)
+                fpay, fenc_ovf = jax.vmap(
+                    lambda row: fr.encode_delta_varint(row, g_byte_cap, b)
+                )(rel)
+                bucket_ovf = bucket_ovf | fenc_ovf.any()
         # Exactness guarantee: if any device's frontier pack, send bucket
         # or compressed stream overflowed, run the whole level densely
         # instead (the predicate is replicated over both grid axes, so
         # every device takes the same branch and collectives stay
         # collective).
-        overflow_any = lax.psum(
-            (pack_ovf | bucket_ovf).astype(jnp.int32), grid_axes) > 0
+        with _scope(_EXCHANGE):
+            overflow_any = lax.psum(
+                (pack_ovf | bucket_ovf).astype(jnp.int32), grid_axes) > 0
 
         def sparse_branch():
-            if use_comp_fold:
-                recvp = fold_sparse_strategy.impl(fpay, row_axis)
-                rec = jax.vmap(lambda row: fr.decode_delta_varint(
-                    row, opts.queue_cap, b))(recvp)              # (r, cap)
-                rec = jnp.where(rec >= 0, rec + me_row * b, -1)
-            else:
-                rec = fold_sparse_strategy.impl(buckets, row_axis)
-            own = jnp.maximum(fr.apply_queue(rec, me_row, b), local_mask)
-            d2, new = _owned_update(dist, own[:, None], level)
-            nwords = fr.pack_bits(new) if fused else None
+            with _scope(_EXCHANGE):
+                if use_comp_fold:
+                    recvp = fold_sparse_strategy.impl(fpay, row_axis)
+                    rec = jax.vmap(lambda row: fr.decode_delta_varint(
+                        row, opts.queue_cap, b))(recvp)          # (r, cap)
+                    rec = jnp.where(rec >= 0, rec + me_row * b, -1)
+                else:
+                    rec = fold_sparse_strategy.impl(buckets, row_axis)
+            with _scope(_FOLD):
+                own = jnp.maximum(fr.apply_queue(rec, me_row, b), local_mask)
+            with _scope(_UPDATE):
+                d2, new = _owned_update(dist, own[:, None], level)
+                nwords = fr.pack_bits(new) if fused else None
             return d2, new, nwords, sparse_bytes
 
         def dense_branch():
@@ -660,7 +737,9 @@ def _make_shard_fn_2d(part2: Partition2D, e_total: int, s: int,
             # of the dense level's
             d2, new, nwords, bb = dense_level(frontier, fwords, dist, level,
                                               src_rowlocal, dst_fold)
-            return d2, new, nwords, bb + expand_sparse_bytes + sieve_gather_bytes
+            with _scope(_UPDATE):
+                bb = bb + expand_sparse_bytes + sieve_gather_bytes
+            return d2, new, nwords, bb
 
         d2, new, nwords, bytes_ = lax.cond(overflow_any, dense_branch,
                                            sparse_branch)
@@ -678,39 +757,48 @@ def _make_shard_fn_2d(part2: Partition2D, e_total: int, s: int,
         hits = jnp.int32(0)
 
         if opts.mode == "dense":
-            dist, new, nwords, bb = dense_level(frontier, fwords, dist,
-                                                level, src_rowlocal,
-                                                dst_fold)
-            modes = modes.at[0].add(1)
+            with _scope(_DENSE):
+                dist, new, nwords, bb = dense_level(frontier, fwords, dist,
+                                                    level, src_rowlocal,
+                                                    dst_fold)
+            with _scope(_UPDATE):
+                modes = modes.at[0].add(1)
             ovf = jnp.bool_(False)
         elif opts.mode == "queue":
-            dist, new, nwords, bb, ovf, hits = queue_level(
-                frontier, fwords, dist, level, src_rowlocal, dst_fold)
-            modes = modes.at[1].add(1)
+            with _scope(_QUEUE):
+                dist, new, nwords, bb, ovf, hits = queue_level(
+                    frontier, fwords, dist, level, src_rowlocal, dst_fold)
+            with _scope(_UPDATE):
+                modes = modes.at[1].add(1)
         else:  # auto: direction-optimizing hybrid on the grid
-            f_verts = lax.psum(frontier.sum(dtype=jnp.int32), grid_axes)
-            f_edges = lax.psum(
-                (out_degree * frontier[:, 0].astype(jnp.int32)
-                 ).sum(dtype=jnp.int32), grid_axes)
-            big = f_verts > jnp.int32(bottom_up_cutoff)
-            tiny = f_edges < jnp.int32(queue_edge_cutoff)
+            with _scope(_DECIDE):
+                f_verts = lax.psum(frontier.sum(dtype=jnp.int32), grid_axes)
+                f_edges = lax.psum(
+                    (out_degree * frontier[:, 0].astype(jnp.int32)
+                     ).sum(dtype=jnp.int32), grid_axes)
+                big = f_verts > jnp.int32(bottom_up_cutoff)
+                tiny = f_edges < jnp.int32(queue_edge_cutoff)
 
             def do_bottom_up():
-                d, nw, nwd, bb = bottom_up_level(frontier, fwords, dist,
-                                                 level, in_src_global,
-                                                 in_dst_local)
+                with _scope(_BOTTOM_UP):
+                    d, nw, nwd, bb = bottom_up_level(frontier, fwords, dist,
+                                                     level, in_src_global,
+                                                     in_dst_local)
                 return (d, nw, nwd, bb, jnp.bool_(False), jnp.int32(2),
                         jnp.int32(0))
 
             def do_queue():
-                d, nw, nwd, bb, ovf, h = queue_level(frontier, fwords, dist,
-                                                     level, src_rowlocal,
-                                                     dst_fold)
+                with _scope(_QUEUE):
+                    d, nw, nwd, bb, ovf, h = queue_level(
+                        frontier, fwords, dist, level, src_rowlocal,
+                        dst_fold)
                 return d, nw, nwd, bb, ovf, jnp.int32(1), h
 
             def do_dense():
-                d, nw, nwd, bb = dense_level(frontier, fwords, dist, level,
-                                             src_rowlocal, dst_fold)
+                with _scope(_DENSE):
+                    d, nw, nwd, bb = dense_level(frontier, fwords, dist,
+                                                 level, src_rowlocal,
+                                                 dst_fold)
                 return (d, nw, nwd, bb, jnp.bool_(False), jnp.int32(0),
                         jnp.int32(0))
 
@@ -721,20 +809,22 @@ def _make_shard_fn_2d(part2: Partition2D, e_total: int, s: int,
             else:
                 dist, new, nwords, bb, ovf, which, hits = lax.cond(
                     big, do_bottom_up, do_dense)
-            modes = modes.at[which].add(1)
+            with _scope(_UPDATE):
+                modes = modes.at[which].add(1)
 
-        # Mask padding vertices (ids >= n_logical can never be visited).
-        new = new * valid_local[:, None].astype(new.dtype)
-        dist = jnp.where(valid_local[:, None], dist, INF)
-        active = lax.psum(new.sum(dtype=jnp.int32), grid_axes) > 0
-        if fused:
-            # next packed generation, pad bits cleared to match the masked
-            # byte frontier exactly
-            fwords = nwords & vwords
-            return (dist, new, fwords, level + 1, active, bytes_acc + bb,
+        with _scope(_UPDATE):
+            # Mask padding vertices (ids >= n_logical can never be visited).
+            new = new * valid_local[:, None].astype(new.dtype)
+            dist = jnp.where(valid_local[:, None], dist, INF)
+            active = lax.psum(new.sum(dtype=jnp.int32), grid_axes) > 0
+            if fused:
+                # next packed generation, pad bits cleared to match the
+                # masked byte frontier exactly
+                fwords = nwords & vwords
+                return (dist, new, fwords, level + 1, active, bytes_acc + bb,
+                        overflowed | ovf, modes, hits_acc + hits)
+            return (dist, new, level + 1, active, bytes_acc + bb,
                     overflowed | ovf, modes, hits_acc + hits)
-        return (dist, new, level + 1, active, bytes_acc + bb,
-                overflowed | ovf, modes, hits_acc + hits)
 
     def _run(src_rowlocal, dst_fold, in_src_global, in_dst_local,
              out_degree, dist0, frontier0, valid_local):

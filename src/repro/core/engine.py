@@ -38,8 +38,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import exchange as ex
 from repro.core import frontier as fr
-from repro.core.bfs import (BFSOptions, BFSStats, INF, _make_shard_fn,
-                            _make_shard_fn_2d, validate_sources)
+from repro.core.bfs import (BFSOptions, BFSStats, INF, INIT_SCOPE,
+                            _make_shard_fn, _make_shard_fn_2d,
+                            validate_sources)
 # chaos layer: a no-op global read unless a FaultPlan is installed
 # (stdlib-only module; degrade.py defers its engine import, no cycle)
 from repro.serve.resilience import faults as _faults
@@ -936,7 +937,8 @@ class BFSEngine:
 
         def init_fn(sources):
             self._bump_trace()
-            return fr.init_dist_frontier(sources, n, part.n_logical)
+            with jax.named_scope(INIT_SCOPE):
+                return fr.init_dist_frontier(sources, n, part.n_logical)
 
         self._init_c = jax.jit(
             init_fn, out_shardings=(sh_vert, sh_vert)).lower(src_sds).compile()
