@@ -216,6 +216,13 @@ def validate_sources(sources, n_logical: int,
     return arr
 
 
+def takes_queue_levels(mode: str, s: int) -> bool:
+    """Whether a plan can run a sparse queue level: queue mode, or the
+    auto hybrid with a single source (id buckets are per vertex, not per
+    source column)."""
+    return mode != "dense" and s == 1
+
+
 def _owned_update(dist, own_cand, level):
     """Owner-computes rule: only unvisited vertices take the new level."""
     unseen = dist == INF
@@ -257,10 +264,19 @@ def _make_shard_fn(part: Partition1D, e_total: int, s: int,
     pack on the critical path between levels.  ``on_tpu`` (the mesh's
     platform) runs that tail as the compiled Pallas kernel; every other
     platform runs its fused jnp twin.
+
+    Plans that can take a queue level (``takes_queue_levels``) receive
+    the shard's CSR view (``row_ptr``, ``csr_dst``; ``frontier.csr_view``)
+    after the kernel operands.  The auto decide phase counts frontier
+    edges from its row offsets, and a queue level compacts the frontier's
+    edges into a buffer of ``min(e_cap, queue edge cutoff)`` slots
+    (auto takes a queue level only below the cutoff; forced queue mode
+    has no bound, so ``e_cap``) instead of passing over every edge slot.
     """
     p, shard, n = part.p, part.shard_size, part.n
     itemsize = 1  # uint8 masks (the "bytes" wire format)
     w_shard = fr.packed_words(shard)
+    csr = takes_queue_levels(opts.mode, s)
     queue_edge_cutoff = max(1, int(opts.queue_threshold * e_total))
     bottom_up_cutoff = max(1, int(opts.bottom_up_threshold * part.n_logical))
     # compressed queue wire: bucket row j encodes ids relative to j*shard
@@ -333,11 +349,17 @@ def _make_shard_fn(part: Partition1D, e_total: int, s: int,
             nwords = fr.pack_bits(new) if fused else None
         return dist, new, nwords, jnp.float32(bottom_up_bytes)
 
-    def queue_level(frontier, dist, level, src_local, dst_global, kargs):
+    def queue_level(frontier, dist, level, src_local, dst_global, kargs,
+                    row_ptr, csr_dst, fdeg):
         with _scope(_EXPAND):
             me = lax.axis_index(axis)
-            valid = dst_global >= 0
-            active = (frontier[src_local, 0] > 0) & valid
+            e_cap = csr_dst.shape[0]
+            slots = (e_cap if opts.mode == "queue"
+                     else min(e_cap, queue_edge_cutoff))
+            if fdeg is None:             # queue mode has no decide phase
+                fdeg = fr.frontier_out_degrees(frontier[:, 0], row_ptr)
+            cand, active = fr.compact_frontier_edges(fdeg, row_ptr, csr_dst,
+                                                     slots)
             hits = jnp.int32(0)
             if sieve:
                 # replicate each shard's coarse visited summary and drop
@@ -346,13 +368,13 @@ def _make_shard_fn(part: Partition1D, e_total: int, s: int,
                 own_sum = fr.sieve_summary(dist[:, 0], sv_bits, sv_bucket)
                 with _scope(_EXCHANGE):
                     gsum = lax.all_gather(own_sum, axis, tiled=True)
-                drop = fr.sieve_lookup(gsum, dst_global, shard, sv_bits,
+                drop = fr.sieve_lookup(gsum, cand, shard, sv_bits,
                                        sv_bucket, sv_words) & active
                 with _scope(_EXCHANGE):
                     hits = lax.psum(drop.sum(dtype=jnp.int32), axis)
                 active = active & ~drop
             buckets, local_mask, _, overflow = fr.build_queue_buckets(
-                dst_global, active, part, me, opts.queue_cap,
+                cand, active, part, me, opts.queue_cap,
                 local_update=opts.local_update, dedupe=opts.dedupe)
             if use_compressed:
                 base = jnp.arange(p, dtype=jnp.int32)[:, None] * shard
@@ -400,7 +422,7 @@ def _make_shard_fn(part: Partition1D, e_total: int, s: int,
         return d2, new, nwords, bytes_, overflow_any, hits
 
     def body(state, src_local, dst_global, in_src_global, in_dst_local,
-             kargs, valid_local, vwords):
+             kargs, row_ptr, csr_dst, valid_local, vwords):
         if fused:
             (dist, frontier, fwords, level, _, bytes_acc, overflowed,
              modes, hits_acc) = state
@@ -420,15 +442,22 @@ def _make_shard_fn(part: Partition1D, e_total: int, s: int,
         elif opts.mode == "queue":
             with _scope(_QUEUE):
                 dist, new, nwords, b, ovf, hits = queue_level(
-                    frontier, dist, level, src_local, dst_global, kargs)
+                    frontier, dist, level, src_local, dst_global, kargs,
+                    row_ptr, csr_dst, None)
             with _scope(_UPDATE):
                 modes = modes.at[1].add(1)
         else:  # auto: direction-optimizing hybrid
             with _scope(_DECIDE):
                 f_verts = lax.psum(frontier.sum(dtype=jnp.int32), axis)
-                f_edges_local = jnp.where(
-                    dst_global >= 0, frontier[src_local, 0], 0
-                ).sum(dtype=jnp.int32)
+                if csr:
+                    fdeg = fr.frontier_out_degrees(frontier[:, 0], row_ptr)
+                    f_edges_local = fdeg.sum(dtype=jnp.int32)
+                else:
+                    # S > 1: no queue level reads the count, and the
+                    # compiler drops this pass over the edge slots
+                    f_edges_local = jnp.where(
+                        dst_global >= 0, frontier[src_local, 0], 0
+                    ).sum(dtype=jnp.int32)
                 f_edges = lax.psum(f_edges_local, axis)
                 big = f_verts > jnp.int32(bottom_up_cutoff)
                 tiny = f_edges < jnp.int32(queue_edge_cutoff)
@@ -444,7 +473,8 @@ def _make_shard_fn(part: Partition1D, e_total: int, s: int,
             def do_queue():
                 with _scope(_QUEUE):
                     d, nw, nwd, b, ovf, h = queue_level(
-                        frontier, dist, level, src_local, dst_global, kargs)
+                        frontier, dist, level, src_local, dst_global, kargs,
+                        row_ptr, csr_dst, fdeg)
                 return d, nw, nwd, b, ovf, jnp.int32(1), h
 
             def do_dense():
@@ -454,7 +484,7 @@ def _make_shard_fn(part: Partition1D, e_total: int, s: int,
                 return (d, nw, nwd, b, jnp.bool_(False), jnp.int32(0),
                         jnp.int32(0))
 
-            if s == 1:
+            if csr:
                 dist, new, nwords, b, ovf, which, hits = lax.cond(
                     big, do_bottom_up,
                     lambda: lax.cond(tiny, do_queue, do_dense))
@@ -482,7 +512,10 @@ def _make_shard_fn(part: Partition1D, e_total: int, s: int,
         if on_trace is not None:
             on_trace()
         kargs = rest[:n_kernel_args]
-        dist0, frontier0, valid_local = rest[n_kernel_args:]
+        row_ptr = csr_dst = None
+        if csr:
+            row_ptr, csr_dst = rest[n_kernel_args:n_kernel_args + 2]
+        dist0, frontier0, valid_local = rest[-3:]
         tail0 = (jnp.int32(1), jnp.bool_(True), jnp.float32(0),
                  jnp.bool_(False), jnp.zeros(3, jnp.int32), jnp.int32(0))
         if fused:
@@ -498,7 +531,8 @@ def _make_shard_fn(part: Partition1D, e_total: int, s: int,
 
         def body_fn(st):
             return body(st, src_local, dst_global, in_src_global,
-                        in_dst_local, kargs, valid_local, vwords)
+                        in_dst_local, kargs, row_ptr, csr_dst, valid_local,
+                        vwords)
 
         st = lax.while_loop(cond, body_fn, state0)
         level = st[lvl_i]

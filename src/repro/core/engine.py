@@ -40,7 +40,7 @@ from repro.core import exchange as ex
 from repro.core import frontier as fr
 from repro.core.bfs import (BFSOptions, BFSStats, INF, INIT_SCOPE,
                             _make_shard_fn, _make_shard_fn_2d,
-                            validate_sources)
+                            takes_queue_levels, validate_sources)
 # chaos layer: a no-op global read unless a FaultPlan is installed
 # (stdlib-only module; degrade.py defers its engine import, no cycle)
 from repro.serve.resilience import faults as _faults
@@ -399,7 +399,8 @@ class BFSPlan:
 
     def estimated_device_bytes(self) -> int:
         """Upper-bound estimate of the device memory a compiled engine of
-        this plan holds live: edge blocks + validity mask (engine-lifetime
+        this plan holds live: edge blocks (with the 1-D CSR view where
+        the plan can take a queue level) + validity mask (engine-lifetime
         residents) plus two generations of (n, S) dist/frontier working
         buffers (one in flight, one being initialized — the dist buffer is
         donated so steady state never holds more).
@@ -450,6 +451,9 @@ class BFSPlan:
             g = self.graph
             n = g.part.n
             edge = 2 * g.p * (g.e_cap + g.in_e_cap) * 4
+            if takes_queue_levels(self.opts.mode, self.num_sources):
+                # the CSR view: row offsets and targets in source order
+                edge += g.p * (g.part.shard_size + 1 + g.e_cap) * 4
             # the packed candidate word array ((p*W, S) uint32) is live
             # across the dense exchange
             wire = (g.p * fr.packed_words(g.part.shard_size) * 4
@@ -544,7 +548,7 @@ def _resolve_sieve(sieve, mode: str, p: int, s: int) -> bool:
     multi-source plans keep it off even when asked).  ``"auto"`` turns
     it on exactly when the filter can save wire bytes: p > 1.
     """
-    if mode == "dense" or s != 1:
+    if not takes_queue_levels(mode, s):
         return False
     if sieve == "auto":
         return p > 1
@@ -916,6 +920,14 @@ class BFSEngine:
                 self._gbufs += _cached(group, lambda ha=host_arrays: tuple(
                     jax.device_put(np.asarray(a), sh_edge)
                     for a in ha()))
+            if plan_.partition == "1d" and takes_queue_levels(opts.mode, s):
+                # the queue level's CSR view, sorted on the device from
+                # the uploaded blocks rather than on the host
+                self._gbufs += _cached("csr", lambda: jax.jit(jax.shard_map(
+                    lambda sl, dg: fr.csr_view(sl, dg, part.shard_size),
+                    mesh=mesh, in_specs=(spec_edge, spec_edge),
+                    out_specs=(spec_edge, spec_edge), check_vma=False))(
+                        *self._gbufs[:2]))
             self._valid = _cached("valid", lambda: jax.device_put(
                 np.arange(n) < part.n_logical, sh_edge))
         n_edge_in = len(self._gbufs)

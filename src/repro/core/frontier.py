@@ -12,11 +12,19 @@ Two statically-shaped frontier representations:
     vertex ids bucketed by owner.  Payload scales with the frontier, not
     with n.  Best for the narrow first/last BFS levels.
 
-``build_queue_buckets`` implements the paper's §5.1 optimization (1): with
-``local_update=True``, candidates owned by the computing shard are applied
-straight to the local bitmap and *excluded* from the send buffers ("added
-conditional check to see if current processor is owner ... resulted into
-relatively lower buffer size").
+The sparse queue level reads the frontier's out-edges through a per-shard
+CSR view of the edge block (``csr_view``: row offsets plus the targets in
+source order, built once per graph on the device).  ``compact_frontier_
+edges`` gathers the frontier's edge targets into a static candidate
+buffer sized by the plan's queue cutoff, so a level's passes scale with
+the frontier rather than with every edge slot of the shard.
+
+``build_queue_buckets`` takes that compacted candidate buffer and
+implements the paper's §5.1 optimization (1): with ``local_update=True``,
+candidates owned by the computing shard are applied straight to the local
+bitmap and *excluded* from the send buffers ("added conditional check to
+see if current processor is owner ... resulted into relatively lower
+buffer size").
 
 The 2-D edge partition reuses both representations per phase:
 ``pack_frontier_ids``/``unpack_row_frontier`` make the expand-phase row
@@ -235,6 +243,70 @@ def expand_bottom_up(frontier_global: jnp.ndarray, in_src_global: jnp.ndarray,
     return cand[:shard]
 
 
+def csr_view(src_local: jnp.ndarray, dst_global: jnp.ndarray, shard: int):
+    """CSR view of one shard's padded out-edge block.
+
+    src_local/dst_global: (E,) int32 padded COO (dst -1 = padding).
+    Returns ``(row_ptr (shard + 1,) int32, csr_dst (E,) int32)``: the
+    targets of local vertex ``v`` are ``csr_dst[row_ptr[v]:row_ptr[v+1]]``
+    in their block order (a stable sort), and the padding slots come last
+    (``row_ptr[shard]`` is the shard's real edge count).
+    """
+    key = jnp.where(dst_global >= 0, src_local, shard)
+    order = jnp.argsort(key, stable=True)
+    row_ptr = jnp.searchsorted(key[order],
+                               jnp.arange(shard + 1, dtype=key.dtype))
+    return row_ptr.astype(jnp.int32), dst_global[order]
+
+
+def frontier_out_degrees(frontier_col: jnp.ndarray,
+                         row_ptr: jnp.ndarray) -> jnp.ndarray:
+    """(shard,) int32 out-degree of each frontier vertex, 0 off the
+    frontier; its sum is the shard's frontier edge count."""
+    return (frontier_col > 0).astype(jnp.int32) * (row_ptr[1:] - row_ptr[:-1])
+
+
+def compact_frontier_edges(fdeg: jnp.ndarray, row_ptr: jnp.ndarray,
+                           csr_dst: jnp.ndarray, slots: int):
+    """The frontier's out-edge targets, packed into ``slots`` leading
+    slots: ``(targets (slots,) int32, active (slots,) bool)``, -1 and
+    False past the frontier's ``fdeg.sum()`` edges, which must not exceed
+    ``slots``.
+
+    Frontier vertex ``v`` fills the slots from ``off[v]`` (the exclusive
+    prefix sum of ``fdeg``), and slot ``j`` of it reads edge
+    ``row_ptr[v] + j - off[v]``.  ``row_ptr[v] - off[v]`` counts the
+    edges of the non-frontier vertices before ``v``, so it never
+    decreases with ``v``: scattering it to each ``off[v]`` and taking a
+    running max hands every slot its vertex's value with one scatter and
+    one scan, no per-slot search.
+    """
+    end = _blocked_scan(fdeg, jnp.cumsum, jnp.add)
+    off = end - fdeg
+    pos = jnp.where(fdeg > 0, off, slots)
+    base = jnp.zeros((slots + 1,), jnp.int32).at[pos].max(
+        row_ptr[:-1] - off)[:slots]
+    j = jnp.arange(slots, dtype=jnp.int32)
+    active = j < end[-1]
+    edge = jnp.where(active, _blocked_scan(base, lax.cummax, jnp.maximum) + j,
+                     0)
+    return jnp.where(active, csr_dst[edge], -1), active
+
+
+def _blocked_scan(x: jnp.ndarray, scan, combine, block: int = 1024):
+    """Inclusive ``scan`` (``jnp.cumsum`` / ``lax.cummax`` and their
+    ``combine``) of a 1-D array of non-negative ints, run as rows of
+    ``block`` plus one scan over the row totals.  Same result as
+    ``scan(x)``; for a TPU v5e target XLA compiles these short scans in
+    about a second, one scan a million elements long in tens."""
+    n = x.shape[0]
+    rows = -(-n // block)
+    y = scan(jnp.pad(x, (0, rows * block - n)).reshape(rows, block), axis=1)
+    carry = scan(y[:, -1])
+    carry = jnp.concatenate([jnp.zeros((1,), x.dtype), carry[:-1]])
+    return combine(y, carry[:, None]).reshape(-1)[:n]
+
+
 def _dedupe_owner(ids: jnp.ndarray, active: jnp.ndarray, owner: jnp.ndarray,
                   sentinel: int, n_owners: int) -> jnp.ndarray:
     """Mask ``owner`` to ``n_owners`` for every duplicate active id.
@@ -286,10 +358,11 @@ def _pack_buckets(ids: jnp.ndarray, owner: jnp.ndarray, n_owners: int,
 def build_queue_buckets(dst_global: jnp.ndarray, active: jnp.ndarray,
                         part: Partition1D, me: jnp.ndarray, cap: int,
                         local_update: bool = True, dedupe: bool = True):
-    """Pack active edge targets into per-owner send buffers.
+    """Pack active candidate targets into per-owner send buffers.
 
-    dst_global: (E,) int32 targets; active: (E,) bool (source in frontier
-    and edge valid).  Returns:
+    dst_global: (C,) int32 global candidate ids, the compacted frontier
+    edge buffer of ``compact_frontier_edges``; active: (C,) bool (a live
+    candidate slot).  Returns:
       buckets:   (p, cap) int32 global ids, -1 padded — ``SendBuf_j``.
       local_mask:(shard,) uint8 — candidates applied locally (opt 5.1-1);
                  all-zero when ``local_update=False`` (they go in buckets).
@@ -298,6 +371,14 @@ def build_queue_buckets(dst_global: jnp.ndarray, active: jnp.ndarray,
                  the dense representation).
     """
     p, shard = part.p, part.shard_size
+    if local_update and p == 1:
+        # the one shard owns every candidate: all apply locally, where
+        # duplicates merge in the scatter-max, and no bucket is filled
+        lid = jnp.where(active, dst_global, shard)
+        local_mask = jnp.zeros((shard + 1,), jnp.uint8).at[lid].max(
+            active.astype(jnp.uint8))[:shard]
+        return (jnp.full((1, cap), -1, jnp.int32), local_mask, jnp.int32(0),
+                jnp.bool_(False))
     owner = jnp.where(active, dst_global // shard, p)
 
     if dedupe:

@@ -89,6 +89,41 @@ def test_estimated_device_bytes_tracks_static_shapes():
     assert p2a.estimated_device_bytes() > p2d.estimated_device_bytes()
 
 
+@pytest.mark.parametrize("mode,s,partition,csr", [
+    ("auto", 1, "1d", True),
+    ("queue", 1, "1d", True),
+    ("auto", 8, "1d", False),       # S > 1 never takes a queue level
+    ("dense", 1, "1d", False),
+    ("auto", 1, "2d", False),       # the 2-D loop has its own queue path
+])
+def test_csr_view_uploaded_only_where_queue_levels_run(mode, s, partition,
+                                                       csr):
+    _, _, g = _graph(n=100)
+    eng = plan(g, BFSOptions(mode=mode), num_sources=s,
+               partition=partition).compile()
+    groups = {key[2] for key in g.__dict__["_device_blocks"].map.keys()}
+    assert ("csr" in groups) == csr
+    # every uploaded group feeds the loop: 4 edge arrays (2 in 2-D), the
+    # 2-D auto bottom-up group's 3, the CSR view's 2
+    n_edge = {"1d": 4, "2d": 2 + 3 * (mode == "auto")}[partition]
+    assert len(eng._gbufs) == n_edge + 2 * csr
+
+
+@pytest.mark.parametrize("mode,s", [("dense", 1), ("queue", 1), ("auto", 1),
+                                    ("auto", 8)])
+def test_estimated_device_bytes_bounds_live_buffers(mode, s):
+    """The estimate charges every buffer the engine keeps on the device:
+    the uploaded groups (the CSR view among them) and two generations of
+    (n, S) dist and frontier working buffers."""
+    _, _, g = _graph(kind="rmat", n=500, edge_factor=6)
+    pl = plan(g, BFSOptions(mode=mode), num_sources=s)
+    eng = pl.compile()
+    res = eng.run(list(range(s)))
+    live = (sum(a.nbytes for a in eng._gbufs) + eng._valid.nbytes
+            + 2 * (res.dist.nbytes + res.dist.size))
+    assert live <= pl.estimated_device_bytes()
+
+
 def test_bottom_up_in_cap_is_exact_under_skew():
     """The budget must charge the bottom-up blocks at their *real* padded
     capacity: under degree skew (star hub) the in-edge blocks out-pad the
