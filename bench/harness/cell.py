@@ -25,7 +25,7 @@ def make_runner(spec, cell: dict, seed: int, seconds: float, devices):
     config = spec.config(cell["config"])
     traffic = spec.traffic(cell["traffic"])
     return RUNNERS[traffic["runner"]](config, traffic, seed, seconds,
-                                      devices)
+                                      devices, spec)
 
 
 def run_cell(spec, name: str, seed: int, seconds: float, trace: bool,

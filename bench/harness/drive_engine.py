@@ -7,7 +7,13 @@ order, each traversal dispatched (``run_async``) and synced
 graph and the keys from that seed instead of the run's: where a window
 holds only a few keys, which keys they are would otherwise set the
 result more than the program does.  The window is timed from the first
-dispatch to the last completion; the depths are fetched after it.
+dispatch to the last completion; the answers are fetched after it.
+
+The configuration brings three things by name: its graph family
+(``generator``, ``generators/<name>.py``), its answer and the check of
+it (``answer``, ``answers/<name>.py``, ``depths`` where not given), and
+its partition (``partition``: ``1d``, the paper's vertex blocks over a
+mesh of ``chips``; or ``2d``, edge blocks over the ``grid`` [r, c]).
 """
 
 from __future__ import annotations
@@ -22,47 +28,50 @@ from harness.common import Stopwatch, rng, say
 
 class EngineRunner:
     def __init__(self, config: dict, traffic: dict, seed: int,
-                 seconds: float, devices):
+                 seconds: float, devices, spec):
         self.config, self.traffic = config, traffic
         self.seed = int(traffic.get("inputs_seed", seed))
         self.devices = list(devices)
         self.s = int(traffic["keys_per_traversal"])
         self.record = {"kind": "engine", "keys_per_traversal": self.s}
         self.engine = None
+        self.grid = grid(config)
+        self.generator = spec.generator(config["generator"])
+        self.answer = spec.answer(config.get("answer", "depths"))
 
     # -------------------------------------------------------------- set-up
     def setup(self, cache_events) -> None:
-        from jax.sharding import Mesh
-
         from repro.core import BFSOptions, plan
         from repro.graphs import shard_graph
 
         sw = Stopwatch("setup")
         with sw.part("generate"):
             self.src, self.dst, self.n = graphgen.generate(
-                self.config, self.seed)
+                self.config, self.seed, self.generator)
         degree = np.bincount(self.src, minlength=self.n)
         self.keys = search_keys(degree, int(self.config["search_keys"]),
                                 self.seed)
         p = int(self.config["chips"])
         with sw.part("shard_graph"):
             graph = shard_graph(self.src, self.dst, self.n, p)
-        mesh = Mesh(np.asarray(self.devices[:p]).reshape(p), ("p",))
         before = cache_events.snapshot()
         with sw.part("compile"):
             pl = plan(graph, BFSOptions(**self.config["options"]),
-                      mesh=mesh, axis="p", num_sources=self.s)
+                      num_sources=self.s, **self._placement(p))
             self.engine = pl.compile()
         after = cache_events.snapshot()
         desc = pl.describe()
+        self.record["partition"] = desc["partition"]
         say("setup", edges=int(self.src.size), n=self.n, p=p, S=self.s,
             first_keys=",".join(str(k) for k in self.keys[:8]),
-            mode=desc["mode"], fused_tail=pl.use_fused_tail,
+            mode=desc["mode"], partition=desc["partition"],
+            fused_tail=pl.use_fused_tail,
             cache_requests=after["requests"] - before["requests"],
             cache_hits=after["hits"] - before["hits"])
+        part = (pl.graph2d if self.grid else graph).part
         self.record["kernel_bytes"] = {
             "fold_update": kernel_bytes.fold_update(
-                graph.part.shard_size, self.s) if pl.use_fused_tail else None}
+                part.shard_size, self.s) if pl.use_fused_tail else None}
         # warm-up: one traversal through the compiled programs, from an
         # isolated vertex where the graph has one (a single level)
         isolated = np.flatnonzero(degree == 0)
@@ -70,6 +79,16 @@ class EngineRunner:
         with sw.part("warm_up"):
             self.engine.run(warm)
         self.record["setup_parts"] = sw.parts
+
+    def _placement(self, p: int) -> dict:
+        """``plan``'s mesh arguments for the configuration's partition."""
+        from jax.sharding import Mesh
+
+        devices = np.asarray(self.devices[:p])
+        if self.grid is None:
+            return {"mesh": Mesh(devices.reshape(p), ("p",)), "axis": "p"}
+        return {"mesh": Mesh(devices.reshape(self.grid), ("rows", "cols")),
+                "partition": "2d"}
 
     # -------------------------------------------------------------- window
     def window(self, seconds: float, annotate) -> None:
@@ -100,7 +119,7 @@ class EngineRunner:
                     "keys": batch, "wall_s": t1 - t0,
                     "levels": stats["levels"],
                     "mode_counts": stats["mode_counts"],
-                    "depths": np.asarray(res.dist_host)})
+                    "answer": self.answer.fetch(res)})
         self.record["traversals"] = traversals
         self.record["attempted"] = len(traversals)
         self.record["failed"] = 0
@@ -115,25 +134,42 @@ class EngineRunner:
 
     # --------------------------------------------------------------- check
     def check(self) -> dict:
-        """Every traversal of the window against the reference; fills
-        each key's component edge count for TEPS."""
+        """Every traversal of the window against the reference, by the
+        configuration's answer; fills each key's component edge count
+        for TEPS from the reference depths, which the answer's check
+        gets too (``depths(keys)``: their ``(n, len(keys))`` columns)."""
         ref = reference.Graph(self.src, self.dst, self.n)
-        used = sorted({key for t in self.record["traversals"]
-                       for key in t["keys"]})
+        traversals = self.record["traversals"]
+        used = sorted({key for t in traversals for key in t["keys"]})
         col = {key: j for j, key in enumerate(used)}
         want = ref.depths(used)
-        mismatched = 0
-        for t in self.record["traversals"]:
-            cols = want[:, [col[key] for key in t["keys"]]]
-            got = t["depths"]
-            if got.shape != cols.shape:
-                mismatched += cols.size
-            else:
-                mismatched += int((got != cols).sum())
+        for t in traversals:
             t["edges"] = [ref.component_edges(want[:, col[key]])
                           for key in t["keys"]]
-            del t["depths"]
-        return {"mismatched_depths": (mismatched, 0)}
+        checks = self.answer.check(
+            ref, traversals, lambda keys: want[:, [col[k] for k in keys]])
+        for t in traversals:
+            del t["answer"]
+        return checks
+
+
+def grid(config: dict):
+    """``None`` for the 1-D partition, ``(r, c)`` for a 2-D grid; any
+    other partition, or a grid that does not multiply to ``chips``, is
+    an error."""
+    partition = config.get("partition", "1d")
+    if partition == "1d":
+        return None
+    if partition != "2d":
+        raise ValueError(f"unknown partition {partition!r}; expected "
+                         "'1d' or '2d'")
+    shape = config.get("grid")
+    chips = int(config["chips"])
+    if (not isinstance(shape, list) or len(shape) != 2
+            or int(shape[0]) * int(shape[1]) != chips):
+        raise ValueError(f"partition '2d' needs a grid [r, c] with r * c "
+                         f"== chips ({chips}); got {shape!r}")
+    return int(shape[0]), int(shape[1])
 
 
 def search_keys(degree: np.ndarray, k: int, seed: int) -> list:

@@ -31,12 +31,20 @@ REPLY_WAIT_S = 60.0          # how long past the window a reply may come
 
 class HttpRunner:
     def __init__(self, config: dict, traffic: dict, seed: int,
-                 seconds: float, devices):
+                 seconds: float, devices, spec):
         self.config, self.traffic, self.seed = config, traffic, int(seed)
         self.seconds = float(seconds)
         self.devices = list(devices)
         self.record = {"kind": "http"}
         self.httpd = None
+        # one lane on one chip, answering depths in its JSON reply
+        if config.get("partition", "1d") != "1d":
+            raise ValueError("the HTTP runner serves the 1-D partition "
+                             f"only; got {config['partition']!r}")
+        if config.get("answer", "depths") != "depths":
+            raise ValueError("the HTTP runner answers depths only; got "
+                             f"{config['answer']!r}")
+        self.generator = spec.generator(config["generator"])
 
     # -------------------------------------------------------------- set-up
     def setup(self, cache_events) -> None:
@@ -49,7 +57,7 @@ class HttpRunner:
         sw = Stopwatch("setup")
         with sw.part("generate"):
             self.src, self.dst, self.n = graphgen.generate(
-                self.config, self.seed)
+                self.config, self.seed, self.generator)
         degree = np.bincount(self.src, minlength=self.n)
         with sw.part("shard_graph"):
             graph = shard_graph(self.src, self.dst, self.n, 1,

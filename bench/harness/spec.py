@@ -2,12 +2,19 @@
 
 ``BENCHMARK.json`` names them; each lives in a file of its own:
 
-* ``configs/<config>.json``  the deployment: generator, chips, options;
-* ``traffic/<mix>.json``     the mix: which runner, and its parameters;
-* ``metrics/<metric>.py``    a per-layer metric: ``read(record)``.
+* ``configs/<config>.json``       the deployment: generator, chips,
+  partition, options, and the answer it is checked by;
+* ``traffic/<mix>.json``          the mix: which runner, and its parameters;
+* ``metrics/<metric>.py``         a per-layer metric: ``read(record)``;
+* ``answers/<answer>.py``         what a traversal answers and how it is
+  checked: ``fetch(result)`` and ``check(graph, traversals, depths)``
+  (a configuration's ``answer`` key, ``depths`` where it gives none);
+* ``generators/<generator>.py``   a graph family: ``num_vertices(cfg)``
+  and the jitted device draw ``draw(cfg, key)`` (its ``generator`` key).
 
-A later change adds a configuration, a mix or a metric by adding such a
-file and an entry in ``BENCHMARK.json``; nothing here names one.
+A later change adds a configuration, a mix, a metric, an answer or a
+graph family by adding such a file and an entry in ``BENCHMARK.json``;
+nothing here names one.
 """
 
 from __future__ import annotations
@@ -55,10 +62,28 @@ class Spec:
 
     def metric_reader(self, name: str):
         """The ``read(record)`` function of ``metrics/<name>.py``."""
-        path = os.path.join(self.bench_dir, "metrics", f"{name}.py")
-        mod_name = "bench_metric_" + "".join(
-            ch if ch.isalnum() else "_" for ch in name)
-        spec = importlib.util.spec_from_file_location(mod_name, path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod.read
+        return load(self.bench_dir, "metrics", name).read
+
+    def answer(self, name: str):
+        """The module of ``answers/<name>.py``."""
+        return load(self.bench_dir, "answers", name)
+
+    def generator(self, name: str):
+        """The module of ``generators/<name>.py``."""
+        return load(self.bench_dir, "generators", name)
+
+
+def load(bench_dir: str, kind: str, name: str):
+    """Import ``<bench_dir>/<kind>/<name>.py``; a name with no file is a
+    ``KeyError`` that lists the names there are."""
+    path = os.path.join(bench_dir, kind, f"{name}.py")
+    if not os.path.isfile(path):
+        have = sorted(f[:-3] for f in os.listdir(os.path.join(bench_dir, kind))
+                      if f.endswith(".py"))
+        raise KeyError(f"no {kind}/{name}.py; have {have}")
+    mod_name = f"bench_{kind}_" + "".join(
+        ch if ch.isalnum() else "_" for ch in name)
+    spec = importlib.util.spec_from_file_location(mod_name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod

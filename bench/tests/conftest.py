@@ -1,11 +1,12 @@
 """Fixtures of the benchmark's tests: a toy copy of the benchmark tree.
 
-The toy tree holds every configuration, mix and metric file of
-``bench/`` and a ``BENCHMARK.json`` whose cells keep their names and
-mixes but run configurations cut to a size the CPU traverses in
-milliseconds.  It also holds the cells that wait for a later benchmark
-change (``HELD``): their files are under ``bench/``, and their runners,
-readers and faults stay tested.
+The toy tree holds every configuration, mix, metric, answer and
+generator file of ``bench/`` and a ``BENCHMARK.json`` whose cells keep
+their names and mixes but run configurations cut to a size the CPU
+traverses in milliseconds.  It also holds what waits for a later
+benchmark change (``HELD``): the served cell and the per-layer metrics
+with no entry yet, whose files are under ``bench/``, so that their
+runners, readers and faults stay tested.
 """
 
 from __future__ import annotations
@@ -35,14 +36,12 @@ def _layer(name, unit, layer, moves, cell, source="program_span"):
             "layer": layer, "moves": moves, "workloads": [cell]}
 
 
-# the entries a later change would add to BENCHMARK.json for each held
-# cell; ``teps`` gains the four-chip cell
+# the entries a later change would add to BENCHMARK.json for the held
+# cell and metrics
 HELD = {
     "workloads": [
         {"name": SERVED, "config": "served_er100k", "traffic": "zipf_open",
          "chips": 1, "why": "held"},
-        {"name": P4, "config": "graph500_s20_p4", "traffic": "key1",
-         "chips": 4, "why": "held"},
     ],
     "end_to_end": [
         {"name": "latency_p50_ms", "unit": "ms", "better": "lower",
@@ -65,7 +64,7 @@ HELD = {
 def make_toy_tree(root: str) -> str:
     """Copy the benchmark's data files under ``root`` with each
     configuration cut to a toy size; returns the toy BENCHMARK.json."""
-    for sub in ("configs", "traffic", "metrics"):
+    for sub in ("configs", "traffic", "metrics", "answers", "generators"):
         shutil.copytree(os.path.join(BENCH_DIR, sub), os.path.join(root, sub))
     for name in os.listdir(os.path.join(root, "configs")):
         path = os.path.join(root, "configs", name)
@@ -84,9 +83,6 @@ def make_toy_tree(root: str) -> str:
         doc = json.load(f)
     for group, entries in HELD.items():
         doc[group] += json.loads(json.dumps(entries))
-    for m in doc["end_to_end"]:
-        if m["name"] == "teps":
-            m["workloads"].append(P4)
     path = os.path.join(root, "BENCHMARK.json")
     with open(path, "w") as f:
         json.dump(doc, f)

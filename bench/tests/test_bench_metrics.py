@@ -124,7 +124,8 @@ def test_inputs_seed_fixes_graph_and_keys(toy_spec):
               if k != "inputs_seed"}
 
     def drawn(traffic, seed):
-        d = EngineRunner(cfg, traffic, seed, 0.1, jax.devices()[:1])
+        d = EngineRunner(cfg, traffic, seed, 0.1, jax.devices()[:1],
+                         toy_spec)
         assert d.seed == traffic.get("inputs_seed", seed)
         return d.seed
 
